@@ -43,6 +43,7 @@ JITTER = 1e-3          # per-rank async compute jitter (s) — the pipeline/
 
 _CHILD = r"""
 import os, sys, json
+os.environ["JAX_PLATFORMS"] = "cpu"   # placeholder devices, never the chip
 os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={sys.argv[1]}"
 sys.path.insert(0, "src")
 import jax, jax.numpy as jnp

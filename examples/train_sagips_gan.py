@@ -39,6 +39,7 @@ from repro.core import gan, workflow
 from repro.core.ensemble import ensemble_response
 from repro.core.sync import MODES, SyncConfig
 from repro.core.workflow import WorkflowConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs.config import ObsConfig
 from repro.problems import available, get_problem
 
@@ -76,6 +77,7 @@ def report_final(problem, gen_stack, data):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=MODES, default="rma_arar_arar")
     ap.add_argument("--problem", choices=available(), default="proxy1d",
@@ -139,7 +141,7 @@ def main():
                     help="'vmap': R simulated ranks in one SPMD program; "
                          "'proc': REAL worker processes over the "
                          "repro.runtime mailbox fabric "
-                         "(jax.distributed on CPU)")
+                         "(jax.distributed; CPU only, never the chip)")
     ap.add_argument("--num-procs", type=int, default=2,
                     help="proc backend: number of worker processes "
                          "(overrides --ranks)")
@@ -233,7 +235,8 @@ def main():
                     else "no new epochs")     # resume already complete
             msg = (f"  rank {s['rank']}: {s['n_epochs'] - s['start_epoch']} "
                    f"epochs in {s['wall_s']:.1f}s "
-                   f"({best}, distributed={s['distributed']})")
+                   f"({best}, distributed={s['distributed']}, "
+                   f"backend={s['backend']})")
             if wcfg.sync.adaptive:
                 msg += (f" max_skew_ema={s['max_skew_ema']:.2f} "
                         f"max_k_eff={s['max_k_eff']}")

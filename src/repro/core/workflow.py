@@ -609,9 +609,8 @@ def make_epoch_fn_shard(mesh, wcfg: WorkflowConfig,
         return out, metrics
 
     spec = P(axes)
-    from ..parallel.sharding import shard_map
-    fn = shard_map(epoch, mesh, in_specs=(spec, spec),
-                   out_specs=(spec, spec))
+    fn = jax.shard_map(epoch, mesh=mesh, in_specs=(spec, spec),
+                       out_specs=(spec, spec), check_vma=False)
     shardings = NamedSharding(mesh, spec)
     # donate the state (mailbox + exchange buffers alias in place)
     return jax.jit(fn, donate_argnums=(0,)), shardings

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .inverse_cdf import interpret_default
+
 NEG_INF = -1e30
 LANES = 128    # TPU vreg lane width; scratch rows are lane-replicated
 
@@ -83,12 +85,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q, k, v, causal: bool = True,
                     window: Optional[int] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q [B,H,Sq,hd]; k/v [B,KV,Sk,hd].  Returns [B,H,Sq,hd].
 
-    interpret=True executes the kernel body on CPU (this host has no TPU);
-    on a TPU runtime pass interpret=False for the compiled Mosaic kernel.
-    """
+    interpret=None auto-selects: compiled Mosaic kernel on TPU, interpreter
+    elsewhere (CPU hosts cannot lower Mosaic)."""
+    if interpret is None:
+        interpret = interpret_default()
     B, H, Sq, hd = q.shape
     KV, Sk = k.shape[1], k.shape[2]
     G = H // KV

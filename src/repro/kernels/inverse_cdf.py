@@ -30,9 +30,9 @@ def _icdf_kernel(u_ref, mu_ref, s_ref, k_ref, y_ref):
 
 
 def interpret_default() -> bool:
-    """Interpret-mode only off-TPU: on a TPU runtime the kernel compiles to
-    a real Mosaic kernel.  (Defaulting to interpret=True everywhere was the
-    hot-path bug that kept the "Pallas" sampler from ever compiling.)"""
+    """The one interpret-mode selector for every kernel in this package:
+    `interpret=None` means the interpreter only off-TPU; on a TPU runtime
+    each kernel compiles to a real Mosaic kernel."""
     return jax.default_backend() != "tpu"
 
 

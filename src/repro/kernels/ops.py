@@ -2,9 +2,9 @@
 
 Model code calls these (when `attn_impl == 'pallas'` / `sampler_impl ==
 'pallas'`); the layout adapters translate between model-layout tensors and
-kernel-layout tensors.  Interpret mode auto-selects per backend: compiled
-Mosaic kernels on TPU, interpreter elsewhere (CPU cannot lower Mosaic).
-Override with REPRO_PALLAS_INTERPRET=0/1.
+kernel-layout tensors.  `interpret=None` resolves through
+`inverse_cdf.interpret_default`: compiled Mosaic kernels on TPU, the
+interpreter elsewhere (CPU cannot lower Mosaic).
 
 Autodiff: each kernel carries a custom_vjp.  Forward runs the Pallas
 kernel; backward of `inverse_cdf` uses the closed-form partials, while the
@@ -15,7 +15,6 @@ paper-relevant memory savings live).
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -29,16 +28,6 @@ from .imaging import blur2d as _blur2d
 from .imaging import mask_apply as _mask_apply
 from . import ref
 
-def _interpret() -> bool:
-    """Resolved lazily so importing this module never initializes the jax
-    backend (the dry-run sets XLA_FLAGS before any jax device touch)."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env != "0"
-    from .inverse_cdf import interpret_default
-    return interpret_default()
-
-
 # ----------------------------------------------------------------------------
 # flash attention (model layout)
 
@@ -50,7 +39,7 @@ def flash_attention(q, k, v, causal: bool = True, window: Optional[int] = None):
     qk = q.reshape(B, S, KV * G, hd).transpose(0, 2, 1, 3)   # [B,H,S,hd]
     kk = k.transpose(0, 2, 1, 3)                             # [B,KV,S,hd]
     vk = v.transpose(0, 2, 1, 3)
-    o = _flash(qk, kk, vk, causal=causal, window=window, interpret=_interpret())
+    o = _flash(qk, kk, vk, causal=causal, window=window)
     return o.transpose(0, 2, 1, 3).reshape(B, S, KV, G, hd)
 
 
@@ -83,7 +72,7 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def ssd_scan(x, dt, A, Bc, Cc, chunk: int = 64):
     """Model layout (see repro.models.ssm.run_ssm)."""
-    return _ssd(x, dt, A, Bc, Cc, chunk=chunk, interpret=_interpret())
+    return _ssd(x, dt, A, Bc, Cc, chunk=chunk)
 
 
 def _ssd_fwd(x, dt, A, Bc, Cc, chunk):
@@ -106,9 +95,8 @@ ssd_scan.defvjp(_ssd_fwd, _ssd_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def inverse_cdf(u, mu, s, k, interpret: Optional[bool] = None):
     """Pipeline layout: u [K,E]; mu/s/k [K].  interpret=None auto-selects
-    per backend (env override via REPRO_PALLAS_INTERPRET)."""
-    return _icdf(u, mu, s, k,
-                 interpret=_interpret() if interpret is None else interpret)
+    per backend."""
+    return _icdf(u, mu, s, k, interpret=interpret)
 
 
 def _icdf_fwd(u, mu, s, k, interpret):
@@ -139,10 +127,8 @@ inverse_cdf.defvjp(_icdf_fwd, _icdf_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def mask_apply(x, m, interpret: Optional[bool] = None):
     """Inpainting occlusion: x [K, P] * m [P].  interpret=None auto-selects
-    per backend (env override via REPRO_PALLAS_INTERPRET)."""
-    return _mask_apply(x, m,
-                       interpret=_interpret() if interpret is None
-                       else interpret)
+    per backend."""
+    return _mask_apply(x, m, interpret=interpret)
 
 
 def _mask_fwd(x, m, interpret):
@@ -164,8 +150,7 @@ mask_apply.defvjp(_mask_fwd, _mask_bwd)
 def blur2d(x, interpret: Optional[bool] = None):
     """Separable 3-tap zero-boundary blur: x [K, H, W] -> [K, H, W].
     interpret=None auto-selects per backend."""
-    return _blur2d(x, interpret=_interpret() if interpret is None
-                   else interpret)
+    return _blur2d(x, interpret=interpret)
 
 
 def _blur_fwd(x, interpret):

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .inverse_cdf import interpret_default
+
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_scr, *,
                 chunk):
@@ -68,11 +70,15 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_scr, *,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A, Bc, Cc, chunk: int = 64, interpret: bool = True):
+def ssd_scan(x, dt, A, Bc, Cc, chunk: int = 64,
+             interpret: bool | None = None):
     """x [B,S,H,P]; dt [B,S,H] (post-softplus); A [H]; Bc/Cc [B,S,N].
 
-    Returns y [B,S,H,P] (without the D*x skip term).
+    Returns y [B,S,H,P] (without the D*x skip term).  interpret=None
+    auto-selects: compiled Mosaic kernel on TPU, interpreter elsewhere.
     """
+    if interpret is None:
+        interpret = interpret_default()
     B, S, H, P = x.shape
     N = Bc.shape[-1]
     Q = min(chunk, S)

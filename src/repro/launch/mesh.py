@@ -13,14 +13,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """Version-compat `jax.make_mesh`: `axis_types` (and the AxisType enum)
-    only exist in newer jax; on e.g. 0.4.37 the kwarg is simply omitted —
-    every mesh axis defaults to auto sharding there anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with every axis in auto sharding mode."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

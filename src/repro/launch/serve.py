@@ -25,6 +25,7 @@ import numpy as np
 import jax
 
 from repro.configs import serving as serving_cfg
+from repro.launch.compile_cache import enable_compile_cache
 from repro.problems import available, get_problem
 from repro.serving import Backpressure, ServingError, SolveService
 
@@ -34,6 +35,7 @@ def _percentile(xs, q):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--problem", action="append", required=True,
                     metavar="NAME[:CKPT_DIR]",

@@ -61,7 +61,7 @@ class ModelConfig:
     remat: bool = True
     remat_policy: str = "full"        # 'full' | 'dots' (save matmul outputs)
     # attention implementation: 'chunked' (flash-equivalent pure jnp, used for
-    # dry-run lowering), 'naive' (small tests), 'pallas' (interpret-mode kernel)
+    # dry-run lowering), 'naive' (small tests), 'pallas' (Pallas kernel)
     attn_impl: str = "chunked"
     attn_chunk: int = 512
 

@@ -159,19 +159,8 @@ def forward(params, batch, cfg: ModelConfig):
         body = jax.checkpoint(period_body, policy=policy)
     else:
         body = period_body
-    from ..parallel.sharding import flag
-    if flag("unroll_periods"):
-        # old XLA (jax 0.4.x) cannot partition a while loop (lax.scan) whose
-        # body touches auto-sharded operands inside a partial-manual
-        # shard_map region — unroll the period loop there instead
-        carry = (x, jnp.zeros((), jnp.float32))
-        for i in range(n_periods):
-            carry, _ = body(carry, jax.tree.map(lambda v: v[i],
-                                                params["periods"]))
-        x, aux = carry
-    else:
-        (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                   params["periods"])
+    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
+                               params["periods"])
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(params, x, cfg)
     return shard(logits, "batch", None, "vocab"), aux

@@ -1,7 +1,5 @@
-"""Sharding utilities — version-compat `shard_map` / `ppermute` wrappers
-and logical-axis rules.  `repro.core.ring.ShardComm` builds its mesh
-collectives on top of these.
-"""
+"""Sharding utilities: logical-axis rules mapping model annotations onto
+mesh axes."""
 from .sharding import axis_rules, shard, logical_to_spec, named_sharding, current_mesh
 
 __all__ = ["axis_rules", "shard", "logical_to_spec", "named_sharding", "current_mesh"]

@@ -10,7 +10,6 @@ import threading
 from typing import Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # logical axis -> physical mesh axes (tuple => sharded over multiple axes)
@@ -183,52 +182,3 @@ def spec_tree_for_params(param_logical):
         lambda ax: NamedSharding(_STATE.mesh, logical_to_spec(ax)),
         param_logical, is_leaf=lambda v: isinstance(v, tuple))
 
-
-def shard_map(f, mesh, in_specs, out_specs, axis_names=None):
-    """Version-compat shard_map.
-
-    Newer jax exposes `jax.shard_map` (kwargs `check_vma`, `axis_names`);
-    jax 0.4.x only has `jax.experimental.shard_map.shard_map` with
-    `check_rep` and the complement-form `auto` for partial-manual axes.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return _shard_map(f, **kw)
-
-
-def ppermute_compat(x, axis_name, perm, idx=None):
-    """`jax.lax.ppermute` that also works inside *partial-manual* shard_map
-    regions on jax 0.4.x, where XLA's SPMD partitioner can partition
-    neither a collective-permute whose operand is sharded over auto
-    subaxes nor the PartitionId behind `jax.lax.axis_index`.
-
-    Fallback: every rank psums its payload into a one-hot [n, ...] table
-    (psum IS partitionable there), then slices its own row by `idx` — an
-    explicit per-rank index the caller threads through sharded data
-    (required on old jax, ignored on new).  Costs n× the payload, so it is
-    only taken on old jax; new jax lowers to the real collective-permute.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.lax.ppermute(x, axis_name, perm)
-    if idx is None:
-        raise ValueError(
-            "ppermute_compat on jax 0.4.x needs an explicit per-rank `idx` "
-            "(jax.lax.axis_index lowers to an unpartitionable PartitionId)")
-    n = len(perm)
-    dst_of = [0] * n
-    for src, dst in perm:
-        dst_of[src] = dst
-    my_dst = jnp.asarray(dst_of)[idx]
-    onehot = (jax.lax.iota(jnp.int32, n) == my_dst).astype(x.dtype)
-    table = jax.lax.psum(
-        onehot.reshape((n,) + (1,) * x.ndim) * x[None], axis_name)
-    return jax.lax.dynamic_index_in_dim(table, idx, 0, keepdims=False)

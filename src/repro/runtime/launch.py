@@ -4,9 +4,11 @@
 this module on the local host, each of which
 
   1. joins the `jax.distributed` CPU cluster (coordinator = process 0,
-     `jax.distributed.initialize`); the mailbox fabric is file-based, so
-     a failed join degrades to a standalone-but-still-correct run and is
-     recorded in the worker's summary,
+     `jax.distributed.initialize`; workers always run with
+     `JAX_PLATFORMS=cpu`, so this backend is not a chip path); the
+     mailbox fabric is file-based, so a failed join degrades to a
+     standalone-but-still-correct run and is recorded in the worker's
+     summary,
   2. rebuilds the SAME initial stacked state as `train_vmap` from the run
      seed and slices out its own rank (bitwise-identical initial point),
   3. runs the per-rank epoch body — jitted `rank_grads` / `rank_apply`
@@ -129,7 +131,9 @@ def run_proc(wcfg, n_outer: int, n_inner: int, n_epochs: int, data, *,
     env = dict(os.environ)
     env["PYTHONPATH"] = src_root + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # CPU only, whatever the caller's environment names: a chip belongs to
+    # one process, so N workers can never share it
+    env["JAX_PLATFORMS"] = "cpu"
 
     procs, logs = [], []
     for r in range(R):
@@ -433,7 +437,8 @@ def _worker_main(rank: int, run_dir: str) -> int:
         hist.pop("skew_ema"), hist.pop("k_eff")
     summary = {
         "rank": rank, "n_epochs": n_epochs, "start_epoch": start,
-        "distributed": distributed, "lockstep": lockstep,
+        "distributed": distributed, "backend": jax.default_backend(),
+        "lockstep": lockstep,
         "jitter": jitter.to_dict(), "wall_s": time.time() - t_run,
         "epoch_s_best": (min(hist["epoch_s"][1:] or hist["epoch_s"])
                          if hist["epoch_s"] else None),
@@ -474,6 +479,8 @@ def main(argv=None) -> int:
     ap.add_argument("--rank", type=int, required=True)
     ap.add_argument("--run-dir", required=True)
     args = ap.parse_args(argv)
+    from ..launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     return _worker_main(args.rank, args.run_dir)
 
 

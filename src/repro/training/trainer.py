@@ -106,12 +106,8 @@ def make_train_state(key, cfg: ModelConfig, tcfg: TrainConfig,
 
     if hier:
         base = init
-        # pod_id: explicit per-pod rank index — old-jax partial-manual
-        # regions cannot lower jax.lax.axis_index (see ppermute_compat)
-        init = lambda k: dict(
-            jax.tree.map(lambda x: jnp.broadcast_to(x, (n_pod,) + x.shape),
-                         base(k)),
-            pod_id=jnp.arange(n_pod, dtype=jnp.int32))
+        init = lambda k: jax.tree.map(
+            lambda x: jnp.broadcast_to(x, (n_pod,) + x.shape), base(k))
 
     if abstract:
         state = jax.eval_shape(init, key)
@@ -143,8 +139,6 @@ def _axes_tree(state, cfg: ModelConfig, tcfg: TrainConfig, hier: bool):
     if hier:
         axes = jax.tree.map(lambda a: ("pod_copy",) + tuple(a), axes,
                             is_leaf=lambda v: isinstance(v, tuple))
-    if "pod_id" in state:
-        axes["pod_id"] = ("pod_copy",)
     return axes
 
 
@@ -219,8 +213,7 @@ def _step_allreduce(state, batch, cfg: ModelConfig, tcfg: TrainConfig):
     return new_state, dict(metrics, loss=loss, gnorm=gnorm)
 
 
-def _ring_exchange(grads, mailbox, step, tcfg: TrainConfig, n_pod: int,
-                   pod_idx=None):
+def _ring_exchange(grads, mailbox, step, tcfg: TrainConfig, n_pod: int):
     """Cross-pod SAGIPS exchange: >=2-D leaves ride the ring every sync_h."""
     perm = [(i, (i + 1) % n_pod) for i in range(n_pod)]
 
@@ -233,9 +226,9 @@ def _ring_exchange(grads, mailbox, step, tcfg: TrainConfig, n_pod: int,
             if g.ndim < 2:          # §V-C: biases / scales stay local
                 return g, mb
             if tcfg.sync_mode == "rma_arar_grouped":
-                new_mb = shd.ppermute_compat(g, "pod", perm, pod_idx)
+                new_mb = jax.lax.ppermute(g, "pod", perm)
                 return comb(g, mb), new_mb
-            recv = shd.ppermute_compat(g, "pod", perm, pod_idx)
+            recv = jax.lax.ppermute(g, "pod", perm)
             return comb(g, recv), mb
         pairs = jax.tree.map(lambda g, mb: leaf(g, mb), fresh, stale)
         g_new = jax.tree.map(lambda pr: pr[0], pairs,
@@ -247,14 +240,6 @@ def _ring_exchange(grads, mailbox, step, tcfg: TrainConfig, n_pod: int,
     if tcfg.sync_mode == "ensemble":
         return grads, mailbox
     due = (step % tcfg.sync_h) == 0
-
-    if not hasattr(jax, "shard_map"):
-        # old XLA (jax 0.4.x) cannot partition a conditional under manual
-        # subaxes: run the exchange unconditionally, select the result
-        g_ex, mb_ex = exchange(grads, mailbox)
-        pick = lambda a, b: jax.tree.map(lambda x, y: jnp.where(due, x, y),
-                                         a, b)
-        return pick(g_ex, grads), pick(mb_ex, mailbox)
 
     def do(args):
         return exchange(*args)
@@ -273,16 +258,14 @@ def _step_hierarchical(state, batch, cfg: ModelConfig, tcfg: TrainConfig,
     loss, metrics, grads = _compute_grads(state1["params"], batch, cfg, tcfg)
     mailbox = state1.get("mailbox",
                          jax.tree.map(lambda g: jnp.zeros((), jnp.float32), grads))
-    pod_idx = state1.get("pod_id")
     if tcfg.sync_mode == "rma_arar_grouped":
         grads_f = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
         synced, mailbox = _ring_exchange(grads_f, state1["mailbox"],
-                                         state1["step"], tcfg, n_pod, pod_idx)
+                                         state1["step"], tcfg, n_pod)
         synced = jax.tree.map(lambda s, g: s.astype(g.dtype), synced, grads)
         extra = {"mailbox": mailbox}
     else:
-        synced, _ = _ring_exchange(grads, grads, state1["step"], tcfg, n_pod,
-                                   pod_idx)
+        synced, _ = _ring_exchange(grads, grads, state1["step"], tcfg, n_pod)
         extra = None
     new_state, gnorm = _apply(state1, synced, tcfg, extra)
     out = jax.tree.map(lambda x: x[None], new_state)
@@ -318,26 +301,19 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
 
     n_pod = mesh.shape["pod"]
 
-    # unroll_periods: old XLA (no jax.shard_map) cannot partition the layer
-    # scan's while loop under manual subaxes either — unroll it there
-    flags = {"embed_onehot": True,
-             "unroll_periods": not hasattr(jax, "shard_map")}
+    flags = {"embed_onehot": True}
 
     def step(state, batch):
         # embed_onehot: XLA cannot partition gathers under manual subaxes
         with shd.axis_rules(mesh, rules, flags=flags):
             return _step_hierarchical(state, batch, cfg, tcfg, n_pod)
 
-    wrapped = shd.shard_map(
-        step, mesh,
+    wrapped = jax.shard_map(
+        step, mesh=mesh,
         in_specs=(P("pod"), P("pod")),
         out_specs=(P("pod"), P()),
-        axis_names={"pod"})
-    # old-jax partial-manual shard_map installs its own input constraints
-    # that clash with explicit pjit in_shardings; the args are committed
-    # with st_shardings already, so inference preserves placement there
-    in_sh = (st_shardings, None) if st_shardings \
-        and hasattr(jax, "shard_map") else None
+        axis_names={"pod"}, check_vma=False)
+    in_sh = (st_shardings, None) if st_shardings else None
     fn = jax.jit(wrapped, in_shardings=in_sh,
                  donate_argnums=(0,) if donate else ())
     return fn, st_shardings

@@ -389,7 +389,7 @@ def test_lint_host_calls_in_traced_core():
         "    np.random.seed(0)\n"
         "    print(x)\n"
         "    os.getcwd()\n"
-        "    os.environ.get('REPRO_PALLAS_INTERPRET')\n"
+        "    os.environ.get('JAX_COMPILATION_CACHE_DIR')\n"
         "    return x\n")
     problems = lint.lint_sources({"core/ring.py": RING_SRC,
                                   "core/gan.py": bad})

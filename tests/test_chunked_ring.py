@@ -164,7 +164,6 @@ from repro.core import workflow
 from repro.core.sync import SyncConfig
 from repro.core.workflow import WorkflowConfig
 from repro.launch.mesh import make_mesh
-from repro.parallel.sharding import shard_map
 from jax.sharding import PartitionSpec as P
 
 mesh = make_mesh((2, 4), ("pod", "data"))
@@ -197,9 +196,9 @@ for label, kw in combos.items():
             o, s = sched.exchange(comm, sq(g), sq(st), e[0])
             return ex(o), ex(s)
 
-        fn = jax.jit(shard_map(body, mesh,
-                               in_specs=(spec, spec, spec),
-                               out_specs=(spec, spec)))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                                   in_specs=(spec, spec, spec),
+                                   out_specs=(spec, spec), check_vma=False))
         st = sched.init_state(R)
         outs = []
         for e in range(3):

@@ -2,10 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                      # offline image: seeded shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.optim import (adam, adamw, sgd, apply_updates,
                          clip_by_global_norm, global_norm)

@@ -3,10 +3,7 @@ residuals, reduced workflow convergence sanity."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                      # offline image: seeded shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import gan, pipeline
 from repro.core.ensemble import ensemble_response, stack_generators

@@ -399,10 +399,7 @@ def test_cadence_composes_with_chunked_scan_and_checkpoint(tmp_path):
 # property tests: FusionSpec pack/unpack over arbitrary layouts (ISSUE 8 —
 # generalizing the fixed generator-shaped cases above)
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 
 def _random_tree(n_leaves, dims, masks, seed):

@@ -383,6 +383,8 @@ def test_proc_lockstep_bitwise_vs_vmapcomm_engine(proc_run_1x2):
         assert_trees_equal(ref[k], out["state"][k], err=f"state[{k!r}]")
     assert all(s["distributed"] for s in out["summaries"]), \
         "workers must join the jax.distributed CPU cluster"
+    assert all(s["backend"] == "cpu" for s in out["summaries"]), \
+        "proc workers are CPU-only whatever the caller's JAX_PLATFORMS"
     assert all(s["lockstep"] for s in out["summaries"])
 
 

@@ -29,10 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                      # offline image: seeded shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint import restore_latest, save_checkpoint
 from repro.core import workflow
@@ -232,7 +229,7 @@ def test_deadband_still_tracks_large_skew_moves():
 @given(st.integers(1, 6),
        st.lists(st.floats(-10.0, 10.0), min_size=2, max_size=60))
 def test_deadband_never_increases_transitions(k_max, skews):
-    """Property (hypothesis shim): on ANY skew sequence the deadband
+    """Property (hypothesis): on ANY skew sequence the deadband
     controller (a) never leaves [1, k_max] and (b) counted from the
     shared initial depth 1, changes depth at most as often as the raw
     rounding controller — every deadband move lands on the raw

@@ -24,10 +24,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.analysis.faults import InterleavingDriver
 from repro.core import gan, workflow
