@@ -79,10 +79,11 @@ def generate_params(gen_params, noise):
     (sigmoid-bounded to the problem's unit cube).  Dispatches on the
     pytree structure: the conv generator is a dict, the MLP a list —
     a static Python check, so each structure traces its own program."""
-    if isinstance(gen_params, dict):
-        from ..models.convgen import conv_generator_apply
-        return conv_generator_apply(gen_params, noise)
-    return mlp_apply(gen_params, noise, final_activation=jax.nn.sigmoid)
+    with jax.named_scope("sagips_gen"):
+        if isinstance(gen_params, dict):
+            from ..models.convgen import conv_generator_apply
+            return conv_generator_apply(gen_params, noise)
+        return mlp_apply(gen_params, noise, final_activation=jax.nn.sigmoid)
 
 
 # discriminator forward compute precisions (ParaGAN's remaining headroom
@@ -111,11 +112,12 @@ def discriminate(disc_params, events, compute_dtype=None):
     the Adam state stay fp32 ("fp32 master", the same discipline as the
     bf16 ring payload).  None is the bitwise-pinned default: no casts at
     all."""
-    if compute_dtype is None:
-        return mlp_apply(disc_params, events)[..., 0]
-    cast = jax.tree.map(lambda p: p.astype(compute_dtype), disc_params)
-    logits = mlp_apply(cast, events.astype(compute_dtype))[..., 0]
-    return logits.astype(jnp.float32)
+    with jax.named_scope("sagips_disc"):
+        if compute_dtype is None:
+            return mlp_apply(disc_params, events)[..., 0]
+        cast = jax.tree.map(lambda p: p.astype(compute_dtype), disc_params)
+        logits = mlp_apply(cast, events.astype(compute_dtype))[..., 0]
+        return logits.astype(jnp.float32)
 
 
 def param_count(params) -> int:

@@ -312,8 +312,9 @@ def make_solver(problem, cfg: SolveConfig):
 
 def _bootstrap(rng, data, n_draw: int):
     """Random draw with replacement (bootstrap, §IV-B)."""
-    idx = jax.random.randint(rng, (n_draw,), 0, data.shape[0])
-    return jnp.take(data, idx, axis=0)
+    with jax.named_scope("sagips_sample"):
+        idx = jax.random.randint(rng, (n_draw,), 0, data.shape[0])
+        return jnp.take(data, idx, axis=0)
 
 
 def rank_grads(state, data_local, wcfg: WorkflowConfig,
@@ -349,9 +350,10 @@ def rank_grads(state, data_local, wcfg: WorkflowConfig,
         d_loss, d_grads = jax.value_and_grad(gan.disc_loss)(
             state["disc"], real, jax.lax.stop_gradient(fake),
             compute_dtype=cdt)
-        d_upd, disc_opt = adam(wcfg.disc_lr).update(d_grads,
-                                                    state["disc_opt"])
-        disc = jax.tree.map(lambda p, u: p + u, state["disc"], d_upd)
+        with jax.named_scope("sagips_apply"):
+            d_upd, disc_opt = adam(wcfg.disc_lr).update(d_grads,
+                                                        state["disc_opt"])
+            disc = jax.tree.map(lambda p, u: p + u, state["disc"], d_upd)
     else:
         d_loss = jnp.full((), jnp.nan, jnp.float32)
         disc, disc_opt = state["disc"], state["disc_opt"]
@@ -390,10 +392,12 @@ def rank_grads(state, data_local, wcfg: WorkflowConfig,
 def rank_apply(state, synced_grads, new_sync, wcfg: WorkflowConfig):
     """Steps 5–6: apply the synchronized generator update.  `new_sync` is
     the schedule's refreshed SyncState pytree (opaque to this layer)."""
-    g_upd, gen_opt = adam(wcfg.gen_lr).update(synced_grads, state["gen_opt"])
-    gen = jax.tree.map(lambda p, u: p + u, state["gen"], g_upd)
-    return dict(state, gen=gen, gen_opt=gen_opt, sync=new_sync,
-                epoch=state["epoch"] + 1)
+    with jax.named_scope("sagips_apply"):
+        g_upd, gen_opt = adam(wcfg.gen_lr).update(synced_grads,
+                                                  state["gen_opt"])
+        gen = jax.tree.map(lambda p, u: p + u, state["gen"], g_upd)
+        return dict(state, gen=gen, gen_opt=gen_opt, sync=new_sync,
+                    epoch=state["epoch"] + 1)
 
 
 # ----------------------------------------------------------------------------
@@ -470,12 +474,13 @@ def _epoch_body_vmap(comm, schedule, wcfg: WorkflowConfig):
             # obs is a Python-level gate (wcfg.obs.metrics is a plain
             # bool): the disabled branch traces the literally-unchanged
             # exchange, so disabled configs lower to byte-identical HLO
-            if wcfg.obs.metrics:
-                synced, new_sync, row = schedule.exchange_with_obs(
-                    comm, gg, ns["sync"], epoch_idx)
-            else:
-                synced, new_sync = schedule.exchange(
-                    comm, gg, ns["sync"], epoch_idx)
+            with jax.named_scope("sagips_exchange"):
+                if wcfg.obs.metrics:
+                    synced, new_sync, row = schedule.exchange_with_obs(
+                        comm, gg, ns["sync"], epoch_idx)
+                else:
+                    synced, new_sync = schedule.exchange(
+                        comm, gg, ns["sync"], epoch_idx)
             out = jax.vmap(lambda s, g, n2: rank_apply(s, g, n2, wcfg))(
                 ns, synced, new_sync)
             if wcfg.obs.metrics:
@@ -585,15 +590,17 @@ def make_epoch_fn_shard(mesh, wcfg: WorkflowConfig,
         def gen_segment(ns, gg):
             # same Python-level obs gate as the vmap body: disabled
             # configs trace the unchanged exchange (HLO-identity pin)
+            with jax.named_scope("sagips_exchange"):
+                if wcfg.obs.metrics:
+                    synced, new_sync, row = schedule.exchange_with_obs(
+                        comm, gg, ns["sync"], ns["epoch"])
+                else:
+                    synced, new_sync = schedule.exchange(
+                        comm, gg, ns["sync"], ns["epoch"])
+            out1 = rank_apply(ns, synced, new_sync, wcfg)
             if wcfg.obs.metrics:
-                synced, new_sync, row = schedule.exchange_with_obs(
-                    comm, gg, ns["sync"], ns["epoch"])
-                out1 = rank_apply(ns, synced, new_sync, wcfg)
                 out1["obs"] = schedule.accumulate_obs(ns["obs"], row)
-                return out1
-            synced, new_sync = schedule.exchange(
-                comm, gg, ns["sync"], ns["epoch"])
-            return rank_apply(ns, synced, new_sync, wcfg)
+            return out1
 
         if ge == 1:
             out = gen_segment(new_state, g_grads)
@@ -711,22 +718,28 @@ def train_vmap(key, wcfg: WorkflowConfig, n_outer: int, n_inner: int,
             if e < start:          # checkpoint landed mid-chunk (e.g. a
                 e, n = start, done - start  # final-epoch save): run only
             #                          the epochs past it, labels stay global
-            state, metrics = run(state, data_per_rank, n)
-            if writer is not None:
-                from ..obs.metrics import chunk_row
-                writer.write_row(chunk_row(done, metrics))
-            for j in range(n):
-                ge = e + j
-                if (checkpoint_every and ge % checkpoint_every == 0) \
-                        or ge == n_epochs - 1:
-                    hist.append(jax.tree.map(lambda x: jnp.asarray(x[j]),
-                                             metrics))
+            # operator spans for `profile_dir` traces; this module may not
+            # import obs.trace (lint check 9), so they call jax.profiler
+            with jax.profiler.StepTraceAnnotation("sagips.train.chunk",
+                                                  step_num=e):
+                state, metrics = run(state, data_per_rank, n)
+            with jax.profiler.TraceAnnotation("sagips.train.flush"):
+                if writer is not None:
+                    from ..obs.metrics import chunk_row
+                    writer.write_row(chunk_row(done, metrics))
+                for j in range(n):
+                    ge = e + j
+                    if (checkpoint_every and ge % checkpoint_every == 0) \
+                            or ge == n_epochs - 1:
+                        hist.append(jax.tree.map(
+                            lambda x: jnp.asarray(x[j]), metrics))
             if checkpoint_dir and (done == n_epochs or (
                     checkpoint_every and done % checkpoint_every == 0)):
                 from ..checkpoint.store import save_checkpoint
-                save_checkpoint(checkpoint_dir, done, state,
-                                metadata={"epochs": done,
-                                          "problem": wcfg.problem})
+                with jax.profiler.TraceAnnotation("sagips.train.checkpoint"):
+                    save_checkpoint(checkpoint_dir, done, state,
+                                    metadata={"epochs": done,
+                                              "problem": wcfg.problem})
     finally:
         if wcfg.obs.profile_dir:
             jax.profiler.stop_trace()

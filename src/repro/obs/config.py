@@ -25,13 +25,15 @@ class ObsConfig:
     metrics_out  JSONL path for chunk-boundary metric flushes
                  (`train_vmap`) / per-epoch rows (proc worker summary).
                  Requires ``metrics=True``.
-    trace_dir    directory for per-rank host-side span traces
-                 (`trace_rank<r>.jsonl`, proc backend only — the SPMD
-                 drivers have no host-side phase worth tracing; merge
-                 with `scripts/obsview.py`).
+    trace_dir    directory for per-rank JSONL span traces
+                 (`trace_rank<r>.jsonl`, proc backend only; merge with
+                 `scripts/obsview.py`).  Every span also reaches the
+                 profiler, with or without this sink.
     profile_dir  `jax.profiler.start_trace` target wrapped around the
-                 `train_vmap` epoch loop (device-side view; the span
-                 tracer is the host-side one).
+                 `train_vmap` epoch loop: the device's operations, tagged
+                 with the epoch program's layer scopes, and on the same
+                 clock the host spans (`sagips.train.chunk`/`.flush`/
+                 `.checkpoint`, and any `obs.trace.span`).
     """
     metrics: bool = False
     metrics_out: Optional[str] = None

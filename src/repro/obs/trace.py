@@ -1,12 +1,18 @@
-"""Host-side span tracer for the free-running proc runtime.
+"""The program's span API: host spans on the profiler's clock, plus
+per-rank JSONL for the free-running proc runtime.
 
-Each worker process installs one `Tracer` writing per-rank JSONL
-(`trace_rank<r>.jsonl`); every line is already a Chrome-trace event
-(``ph="X"`` complete spans, ``ph="C"`` counters, ``ph="i"`` instants),
-so merging rank files into a Perfetto/`chrome://tracing`-loadable
-document is pure concatenation plus metadata (`merge_traces`).
+`span(name, cat, **args)` always opens a `jax.profiler.TraceAnnotation`
+(a TraceMe): inside a `jax.profiler` session the span lands on the same
+trace as the device's operations, with `args` as its stats; with no
+session active it costs a few hundred nanoseconds and records nothing.
+When a `Tracer` is installed (a proc worker with
+`ObsConfig(trace_dir=...)`), the same span is also written as one JSONL
+line.  Every line is already a Chrome-trace event (``ph="X"`` complete
+spans, ``ph="C"`` counters), so merging rank files into a
+Perfetto/`chrome://tracing`-loadable document is pure concatenation plus
+metadata (`merge_traces`).
 
-Design constraints:
+Design constraints of the JSONL sink:
 
   * Wall-clock timestamps (``time.time()``, microseconds) so spans from
     DIFFERENT processes land on one comparable timeline — durations use
@@ -14,13 +20,13 @@ Design constraints:
   * Crash-safe: one `json.dumps` + newline + flush per event; a killed
     worker loses at most a torn trailing line, which `load_events`
     skips.
-  * Near-zero disabled overhead: module-level `span()` returns a shared
-    `nullcontext` when no tracer is installed — one attribute load and
-    one branch.
 
 Traced-core modules (core/sync.py, core/workflow.py, core/ring.py) must
 NOT import this module (repo-lint check 9): inside jit, telemetry goes
-through the metrics pytree instead.
+through the metrics pytree, and the epoch program's layers carry
+`jax.named_scope`s (`sagips_sample`, `sagips_gen`, `sagips_disc`,
+`sagips_exchange`, `sagips_apply`) that the device trace reports per
+operation.
 """
 import contextlib
 import json
@@ -28,7 +34,9 @@ import threading
 import time
 from typing import Optional
 
-__all__ = ["Tracer", "current_tracer", "install", "instant", "counter",
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Tracer", "current_tracer", "install", "counter",
            "load_events", "merge_traces", "span", "uninstall",
            "write_chrome_trace"]
 
@@ -76,11 +84,6 @@ class Tracer:
                 "args": dict(args, depth=self._depth),
             })
 
-    def instant(self, name: str, cat: str = "runtime", **args):
-        self._emit({"name": name, "cat": cat, "ph": "i", "s": "t",
-                    "ts": round(time.time() * 1e6, 3),
-                    "pid": self.rank, "tid": 0, "args": args})
-
     def counter(self, name: str, value, cat: str = "metric"):
         self._emit({"name": name, "cat": cat, "ph": "C",
                     "ts": round(time.time() * 1e6, 3),
@@ -94,12 +97,10 @@ class Tracer:
 
 
 # ----------------------------------------------------------------------------
-# module-level installation — instrumented call sites go through these,
-# so the disabled path costs one attribute load and one branch
+# module-level installation — instrumented call sites go through these
 
 
 _TRACER: Optional[Tracer] = None
-_NULL_SPAN = contextlib.nullcontext()
 
 
 def install(tracer: Tracer):
@@ -119,16 +120,18 @@ def current_tracer() -> Optional[Tracer]:
 
 
 def span(name: str, cat: str = "runtime", **args):
+    """A profiler span named `name` with `args` as its stats; also a JSONL
+    line (category `cat`) when a `Tracer` is installed."""
     t = _TRACER
     if t is None:
-        return _NULL_SPAN
-    return t.span(name, cat, **args)
+        return TraceAnnotation(name, **args)
+    return _both(t, name, cat, args)
 
 
-def instant(name: str, cat: str = "runtime", **args):
-    t = _TRACER
-    if t is not None:
-        t.instant(name, cat, **args)
+@contextlib.contextmanager
+def _both(tracer: Tracer, name: str, cat: str, args: dict):
+    with TraceAnnotation(name, **args), tracer.span(name, cat, **args):
+        yield
 
 
 def counter(name: str, value, cat: str = "metric"):

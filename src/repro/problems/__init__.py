@@ -121,10 +121,12 @@ def synthetic_events(problem: InverseProblem, gen_params, key,
     k1, k2 = jax.random.split(key)
     noise = jax.random.normal(k1, (n_param_samples, gan.NOISE_DIM))
     params = gan.generate_params(gen_params, noise)
-    u = jax.random.uniform(
-        k2, (n_param_samples, events_per_sample, problem.noise_channels))
-    return problem.sample_events(params, u, impl=impl,
-                                 interpret=interpret), params
+    with jax.named_scope("sagips_sample"):
+        u = jax.random.uniform(
+            k2, (n_param_samples, events_per_sample, problem.noise_channels))
+        events = problem.sample_events(params, u, impl=impl,
+                                       interpret=interpret)
+    return events, params
 
 
 # ----------------------------------------------------------------------------

@@ -15,6 +15,13 @@ Request lifecycle (docs/serving.md has the full diagram):
         -> solve(gen_stack, ys, mask)  `core.workflow.make_solver` output
         -> Ticket.resolve              client unblocks with params/sigma
 
+Each phase is a profiler span (`obs.trace.span`): `sagips.solve.submit`
+on the submitter's thread, and per `step()` a `sagips.solve.step` holding
+`.drain`, `.compile` (cache misses only), `.assemble`, `.dispatch`,
+`.fetch` (waiting for the answer) and `.resolve`.  `.assemble` carries
+the batch's `n`, `bucket`, and the queue waits of its requests
+(`wait_sum_us`, `wait_max_us`: admission to drain).
+
 The service separates WHAT a solve computes (`make_solver`, built in
 `core.workflow` and shared with the trainer's final report) from WHERE it
 runs (this module: batching, warm pool, admission control).  All jit goes
@@ -35,6 +42,7 @@ from .bucketing import bucket_for, pad_events, validate_buckets
 from .cache import CompileCache, jit_compile
 from .queue import Backpressure, BoundedRequestQueue
 from ..obs.counters import Counters
+from ..obs.trace import span
 from ..core import gan
 from ..core.workflow import SolveConfig, make_solver
 from ..problems import get_problem
@@ -81,7 +89,11 @@ class Ticket:
         self.problem = problem
         self.bucket = bucket
         self.n_events = n_events
-        self.t_submit = time.perf_counter()   # queue-inclusive latency base
+        # admission stamp and queue-inclusive latency base: `submit` makes
+        # the ticket as it hands the request to the queue, and a rejected
+        # request's ticket is dropped, so every drained ticket was admitted
+        # here
+        self.t_submit = time.perf_counter()
         self._done = threading.Event()
         self._result: Optional[dict] = None
         self._error: Optional[BaseException] = None
@@ -199,16 +211,17 @@ class SolveService:
                 f"problem {problem_name!r} is not registered with this "
                 f"service (registered: {list(self.problems())}); call "
                 f"register_problem first")
-        problem, _ = self._problems[problem_name]
-        y = np.asarray(y, dtype=np.float32)
-        if y.ndim != 2 or y.shape[1] != problem.obs_dim:
-            raise ServingError(
-                f"{problem_name!r} observations must be [n_events, "
-                f"{problem.obs_dim}], got shape {y.shape}")
-        bucket = bucket_for(y.shape[0], self.cfg.buckets)
-        padded, mask = pad_events(y, bucket)
-        ticket = Ticket(problem_name, bucket, y.shape[0])
-        self.queue.submit((problem_name, bucket), (padded, mask, ticket))
+        with span("sagips.solve.submit"):
+            problem, _ = self._problems[problem_name]
+            y = np.asarray(y, dtype=np.float32)
+            if y.ndim != 2 or y.shape[1] != problem.obs_dim:
+                raise ServingError(
+                    f"{problem_name!r} observations must be [n_events, "
+                    f"{problem.obs_dim}], got shape {y.shape}")
+            bucket = bucket_for(y.shape[0], self.cfg.buckets)
+            padded, mask = pad_events(y, bucket)
+            ticket = Ticket(problem_name, bucket, y.shape[0])
+            self.queue.submit((problem_name, bucket), (padded, mask, ticket))
         return ticket
 
     # -- server side ---------------------------------------------------------
@@ -222,11 +235,12 @@ class SolveService:
         problem, gen_stack = self._problems[problem_name]
 
         def builder():
-            fn = jit_compile(make_solver(problem, self.cfg.solve))
-            ys0 = jnp.zeros((self.cfg.max_batch, bucket, problem.obs_dim),
-                            jnp.float32)
-            m0 = jnp.zeros((self.cfg.max_batch, bucket), bool)
-            jax.block_until_ready(fn(gen_stack, ys0, m0))
+            with span("sagips.solve.compile", bucket=bucket):
+                fn = jit_compile(make_solver(problem, self.cfg.solve))
+                ys0 = jnp.zeros((self.cfg.max_batch, bucket,
+                                 problem.obs_dim), jnp.float32)
+                m0 = jnp.zeros((self.cfg.max_batch, bucket), bool)
+                jax.block_until_ready(fn(gen_stack, ys0, m0))
             return fn
 
         return self.cache.get((problem_name, bucket), builder)
@@ -240,36 +254,46 @@ class SolveService:
     def step(self) -> int:
         """Drain and serve ONE batch.  Returns the number of requests
         served (0 = queue empty)."""
-        key = self.queue.next_key()
-        if key is None:
-            return 0
-        items = self.queue.drain(key, self.cfg.max_batch)
-        if not items:
-            return 0
-        problem_name, bucket = key
-        B = self.cfg.max_batch
-        tickets = [t for (_, _, t) in items]
-        try:
-            fn = self._executable(problem_name, bucket)
-            problem, gen_stack = self._problems[problem_name]
-            ys = np.zeros((B, bucket, problem.obs_dim), np.float32)
-            mask = np.zeros((B, bucket), bool)   # padding rows: all-False
-            for i, (py, pm, _) in enumerate(items):
-                ys[i], mask[i] = py, pm
-            out = fn(gen_stack, jnp.asarray(ys), jnp.asarray(mask))
-            out = jax.tree.map(np.asarray, out)
-            now = time.perf_counter()
-            for i, t in enumerate(tickets):
-                t.resolve({k: v[i] for k, v in out.items()})
-                # queue-inclusive request latency, bucketed per lane
-                self.counters.observe(f"{problem_name}/b{bucket}",
-                                      now - t.t_submit)
-        except Exception as e:       # noqa: BLE001 — tickets must unblock
-            for t in tickets:
-                t.fail(e)
-            raise
-        self.served += len(tickets)
-        return len(tickets)
+        with span("sagips.solve.step"):
+            with span("sagips.solve.drain"):
+                key = self.queue.next_key()
+                items = [] if key is None else self.queue.drain(
+                    key, self.cfg.max_batch)
+            t_drain = time.perf_counter()
+            if not items:
+                return 0
+            problem_name, bucket = key
+            B = self.cfg.max_batch
+            tickets = [t for (_, _, t) in items]
+            waits = [t_drain - t.t_submit for t in tickets]
+            try:
+                fn = self._executable(problem_name, bucket)
+                with span("sagips.solve.assemble", n=len(tickets),
+                          bucket=bucket, wait_sum_us=1e6 * sum(waits),
+                          wait_max_us=1e6 * max(waits)):
+                    problem, gen_stack = self._problems[problem_name]
+                    ys = np.zeros((B, bucket, problem.obs_dim), np.float32)
+                    mask = np.zeros((B, bucket), bool)  # padding: all-False
+                    for i, (py, pm, _) in enumerate(items):
+                        ys[i], mask[i] = py, pm
+                    ys, mask = jnp.asarray(ys), jnp.asarray(mask)
+                with span("sagips.solve.dispatch"):
+                    out = fn(gen_stack, ys, mask)
+                with span("sagips.solve.fetch"):
+                    out = jax.tree.map(np.asarray, out)
+                with span("sagips.solve.resolve"):
+                    now = time.perf_counter()
+                    for i, t in enumerate(tickets):
+                        t.resolve({k: v[i] for k, v in out.items()})
+                        # queue-inclusive request latency, bucketed per lane
+                        self.counters.observe(f"{problem_name}/b{bucket}",
+                                              now - t.t_submit)
+                    self.served += len(tickets)
+            except Exception as e:   # noqa: BLE001 — tickets must unblock
+                for t in tickets:
+                    t.fail(e)
+                raise
+            return len(tickets)
 
     def run_until_empty(self) -> int:
         """Drain everything queued; returns total requests served."""

@@ -6,10 +6,12 @@ Three channels, one invariant each (`scripts/check.sh --obs`):
             a DISABLED run lowers to byte-identical HLO (vmap and shard),
             and an ENABLED run leaves the golden proxy1d trajectory
             bitwise untouched — telemetry may never perturb training;
-  tracing   the host span tracer is crash-safe line-at-a-time JSONL in
-            Chrome-trace event form: span nesting depths, torn-tail
-            tolerance and the Perfetto merge round-trip are pinned, and
-            the uninstalled path is a shared nullcontext (no-op);
+  tracing   every `obs.trace.span` is a profiler TraceAnnotation, and
+            with a tracer installed also crash-safe line-at-a-time JSONL
+            in Chrome-trace event form: span nesting depths, torn-tail
+            tolerance and the Perfetto merge round-trip are pinned; the
+            epoch program's matmuls each carry one of the five layer
+            scopes in their HLO locations;
   serving   counters/latency histograms behind `SolveService.snapshot()`,
             with the queue recording a rejection INSIDE its lock before
             `Backpressure` propagates (audited under a Gate
@@ -23,6 +25,7 @@ summaries.
 import importlib.util
 import json
 import os
+import re
 import subprocess
 import sys
 import threading
@@ -109,19 +112,21 @@ SCHEDULES = {
 }
 
 
-def _lower_vmap(wcfg, R=4):
+def _lower_vmap(wcfg, R=4, debug_info=False):
     state = workflow.init_state(jax.random.PRNGKey(0), R, wcfg)
     data = wcfg.problem_obj.make_reference_data(jax.random.PRNGKey(1), 100)
     fn = workflow.make_epoch_fn_vmap(2, R // 2, wcfg)
-    return fn.lower(state, jnp.stack([data] * R)).as_text()
+    return fn.lower(state, jnp.stack([data] * R)).as_text(
+        debug_info=debug_info)
 
 
-def _lower_shard(wcfg):
+def _lower_shard(wcfg, debug_info=False):
     mesh = make_mesh((1, 1), ("pod", "data"))
     state = workflow.init_state(jax.random.PRNGKey(0), 1, wcfg)
     data = wcfg.problem_obj.make_reference_data(jax.random.PRNGKey(1), 100)
     fn, _shardings = workflow.make_epoch_fn_shard(mesh, wcfg)
-    return fn.lower(state, jnp.stack([data] * 1)).as_text()
+    return fn.lower(state, jnp.stack([data] * 1)).as_text(
+        debug_info=debug_info)
 
 
 @pytest.mark.parametrize("label", sorted(SCHEDULES))
@@ -152,6 +157,51 @@ def test_enabled_metrics_changes_lowering_only_when_on():
         _lower_vmap(small_wcfg(sync, obs=ObsConfig(metrics=True)))
     assert _lower_shard(small_wcfg(sync)) != \
         _lower_shard(small_wcfg(sync, obs=ObsConfig(metrics=True)))
+
+
+# ----------------------------------------------------------------------------
+# layer scopes: every matmul of the epoch program names its layer
+
+LAYER_SCOPE = re.compile(
+    r"sagips_(?:sample|gen|disc|exchange|apply)(?![A-Za-z0-9_])")
+LOC_DEF = re.compile(r"^(#loc\d*) = (.*)$", re.M)
+MATMUL = re.compile(r"stablehlo\.(?:dot_general|convolution)\b.*"
+                    r"loc\((.*)\)\s*$")
+
+
+def _op_name(defs, loc, depth=0):
+    """The `op_name` path of a location (the first string that names a
+    jitted function's op), following `#loc` aliases."""
+    for m in re.finditer(r'"([^"]*)"|(#loc\d*)', loc):
+        if m.group(1) is not None and m.group(1).startswith("jit("):
+            return m.group(1)
+        if m.group(2) and depth < 50:
+            found = _op_name(defs, defs.get(m.group(2), ""), depth + 1)
+            if found:
+                return found
+    return None
+
+
+@pytest.mark.parametrize("body", ["vmap", "shard"])
+@pytest.mark.parametrize("problem", ["proxy1d", "imaging"])
+def test_epoch_program_matmuls_carry_a_layer_scope(body, problem):
+    """The five `jax.named_scope`s reach the lowered program: every
+    dot_general and convolution's location names one of them (the device
+    trace attributes operations by this path)."""
+    wcfg = small_wcfg(SyncConfig(mode="rma_arar_arar", h=2),
+                      problem=problem)
+    lower = _lower_vmap if body == "vmap" else _lower_shard
+    text = lower(wcfg, debug_info=True)
+    defs = dict(LOC_DEF.findall(text))
+    names = [_op_name(defs, m.group(1))
+             for m in map(MATMUL.search, text.splitlines()) if m]
+    assert len(names) >= 8
+    unscoped = [n for n in names if not (n and LAYER_SCOPE.search(n))]
+    assert not unscoped, unscoped[:5]
+    scopes = {LAYER_SCOPE.findall(n)[-1] for n in names}
+    assert {"sagips_gen", "sagips_disc"} <= scopes
+    # the debug-free text the HLO-identity pins compare carries no scope
+    assert "sagips_disc" not in lower(wcfg)
 
 
 # ----------------------------------------------------------------------------
@@ -273,14 +323,16 @@ def test_tracer_span_nesting_and_containment(tmp_path):
 def test_tracer_crash_safe_skips_torn_tail(tmp_path):
     p = str(tmp_path / "t.jsonl")
     tr = Tracer(p)
-    tr.instant("checkpoint")
+    with tr.span("checkpoint"):
+        pass
     tr.counter("k_eff", 2)
     tr.close()
     with open(p, "a") as f:                  # a worker killed mid-write
         f.write('{"name": "torn", "ph": "X", "ts": 12')
     events, skipped = load_events(p)
     assert skipped == 1
-    assert [e["ph"] for e in events] == ["i", "C"]
+    assert [e["ph"] for e in events] == ["X", "C"]
+    assert events[0]["name"] == "checkpoint"
     assert events[1]["args"] == {"k_eff": 2}
 
 
@@ -293,14 +345,67 @@ def test_tracer_closed_emit_is_silent(tmp_path):
     assert events == []
 
 
-def test_module_span_is_nullcontext_when_uninstalled():
+def test_module_span_is_profiler_annotation_when_uninstalled():
+    """Without a tracer a span is a bare profiler TraceAnnotation (no
+    JSONL sink to feed), and a counter is silently dropped."""
     assert obs_trace.current_tracer() is None
     s1 = obs_trace.span("a")
     s2 = obs_trace.span("b", cat="wait", arg=1)
-    assert s1 is s2                          # ONE shared nullcontext
+    assert isinstance(s1, jax.profiler.TraceAnnotation)
+    assert isinstance(s2, jax.profiler.TraceAnnotation)
+    assert s1 is not s2
     with s1:
-        obs_trace.instant("noop")
-        obs_trace.counter("noop", 1.0)       # all silently dropped
+        with s2:
+            obs_trace.counter("noop", 1.0)
+    assert obs_trace.current_tracer() is None
+
+
+def _profiled(tmp_path, body):
+    """Run `body()` inside a CPU profiler session; the host events by name
+    as {name: [stats dict, ...]}."""
+    import glob
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "prof" / "**" / "*.xplane.pb"),
+                      recursive=True)
+    events = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    events.setdefault(e.name, []).append(dict(e.stats))
+    return events
+
+
+def test_module_span_reaches_the_profiler_without_a_tracer(tmp_path):
+    def body():
+        with obs_trace.span("sagips.test.outer", cat="wait", epoch=3):
+            with obs_trace.span("sagips.test.inner"):
+                pass
+    events = _profiled(tmp_path, body)
+    assert events["sagips.test.outer"] == [{"epoch": 3}]
+    assert len(events["sagips.test.inner"]) == 1
+    assert obs_trace.current_tracer() is None
+
+
+def test_module_span_reaches_profiler_and_jsonl_with_a_tracer(tmp_path):
+    p = str(tmp_path / "t.jsonl")
+    obs_trace.install(Tracer(p, rank=2))
+
+    def body():
+        with obs_trace.span("sagips.test.exchange", cat="wire", epoch=5):
+            pass
+    events = _profiled(tmp_path, body)
+    obs_trace.uninstall().close()
+    assert events["sagips.test.exchange"] == [{"epoch": 5}]
+    lines, skipped = load_events(p)
+    assert skipped == 0 and len(lines) == 1
+    ev = lines[0]
+    assert ev["name"] == "sagips.test.exchange" and ev["cat"] == "wire"
+    assert ev["pid"] == 2 and ev["args"] == {"epoch": 5, "depth": 0}
 
 
 def test_chrome_trace_merge_roundtrip(tmp_path):
